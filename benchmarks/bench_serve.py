@@ -83,7 +83,9 @@ print("JSON" + json.dumps(out))
 
 
 def _collect(quick: bool) -> list:
-    env = dict(os.environ,
+    # the child measures virtual CPU devices by design: pin it to the CPU
+    # so it never reaches for an accelerator the parent may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.join(_ROOT, "src") + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     body = textwrap.dedent(_BODY % {"quick": quick})

@@ -51,6 +51,8 @@ def main() -> None:
                     help="where to write BENCH_*.json (default: repo root)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_comm_volume, bench_cost_model,
                             bench_kernels, bench_serve, bench_sharding)
     # comm/kernels print their rows from the JSON records below — no

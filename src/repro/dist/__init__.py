@@ -69,12 +69,8 @@ op, on a fake CPU mesh with no real devices:
   trace-time ``collectives.record_collectives()`` note and vice versa
   (the accounted wrappers in ``dist.collectives`` are the only legal
   spelling of raw collectives — enforced by an AST lint).
-
-Importing this package also installs a version-tolerant ``jax.shard_map``
-alias on JAX builds that only export the experimental spelling.
 """
 
-from repro.dist._compat import install_jax_alias, shard_map
 from repro.dist.collectives import (
     SCHEDULES,
     CollectiveNote,
@@ -113,8 +109,6 @@ from repro.dist.matmul import (
 )
 from repro.dist.pipeline import pipelined_apply
 
-install_jax_alias()
-
 # dist.train sits above the model/optimizer stack (it imports models.cnn
 # and train.step, which themselves import repro.dist lazily); re-export it
 # lazily so importing the primitives package neither pulls in the whole
@@ -131,7 +125,7 @@ def __getattr__(name):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 __all__ = [
-    "SCHEDULES", "shard_map", "CollectiveNote", "record_collectives",
+    "SCHEDULES", "CollectiveNote", "record_collectives",
     "gather_axis", "ring_all_gather",
     "ring_reduce", "ring_reduce_scatter", "ring_scatter_reduce",
     "ring_zip", "scatter_axis", "make_mesh",

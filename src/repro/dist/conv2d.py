@@ -83,7 +83,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist._compat import shard_map
+from jax import shard_map
 from repro.dist.collectives import (SCHEDULES, gather_axis, make_mesh,
                                     ppermute, psum, ring_reduce,
                                     ring_scatter_reduce, ring_zip,
@@ -469,7 +469,7 @@ def _conv2d_raw(x, w, mesh, schedule, stride, plans, pallas=True):
         in_specs=(P("b", ("c", "k"), "h", "w"),
                   P("k", ("c", "b"), None, None)),
         out_specs=P("b", "k", "h", "w"),
-        check_rep=False)
+        check_vma=False)
     return fn(x, w)
 
 
@@ -494,7 +494,7 @@ def _conv2d_bwd(mesh, schedule, stride, plans, res, g):
                   P("b", "k", "h", "w")),
         out_specs=(P("b", ("c", "k"), "h", "w"),
                    P("k", ("c", "b"), None, None)),
-        check_rep=False)
+        check_vma=False)
     return fn(x, w, g)
 
 
@@ -616,7 +616,7 @@ def conv_train_comm_elems(x_shape, w_shape, grid, *, stride=(1, 1),
     ``save_gathered=True`` models the residual-saving (native) VJP: the
     replay terms vanish (the gathered operands are stored, not
     re-fetched), but the transpose of the c-axis all-reduce is no longer
-    the free broadcast the custom VJP exploits — under ``check_rep=False``
+    the free broadcast the custom VJP exploits — under ``check_vma=False``
     the native transpose cannot prove the cotangent replicated and psums
     it once (``psum_out_bwd``, the forward ``reduce_out`` volume again).
     ``schedule="ring2"`` (on supported grids) scatters dKer over b

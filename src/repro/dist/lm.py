@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist._compat import shard_map
+from jax import shard_map
 from repro.dist.collectives import SCHEDULES, psum
 from repro.dist.matmul import (matmul_comm_elems, matmul_distributed,
                                matmul_grid_divides, matmul_mem_elems)
@@ -149,7 +149,7 @@ def expert_ffn_distributed(xg, disp, comb, w_gate, w_up, w_down,
         in_specs=(P(), P(None, None, "c", None), P(None, None, "c", None),
                   P("c", None, "n"), P("c", None, "n"), P("c", "n", None)),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(xg, disp, comb, w_gate, w_up, w_down)
 
 

@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist._compat import shard_map
+from jax import shard_map
 from repro.dist.collectives import (SCHEDULES, gather_axis, make_mesh,
                                     ppermute, psum, ring_reduce,
                                     ring_scatter_reduce, ring_zip,
@@ -292,7 +292,7 @@ def _matmul_raw(x, w, mesh, schedule, pallas=True):
         mesh=mesh,
         in_specs=(P("m", ("c", "n")), P(("c", "m"), "n")),
         out_specs=P("m", "n"),
-        check_rep=False)
+        check_vma=False)
     return fn(x, w)
 
 
@@ -315,7 +315,7 @@ def _matmul_bwd(mesh, schedule, res, g):
         mesh=mesh,
         in_specs=(P("m", ("c", "n")), P(("c", "m"), "n"), P("m", "n")),
         out_specs=(P("m", ("c", "n")), P(("c", "m"), "n")),
-        check_rep=False)
+        check_vma=False)
     return fn(x, w, g)
 
 
@@ -370,7 +370,7 @@ def matmul_train_comm_elems(M: int, C: int, N: int, grid, *,
     ``save_gathered=True`` drops the replay terms (the gathered operands
     are stored as residuals, not re-fetched) but pays the forward
     ``reduce_out`` volume once more: the native transpose of the c-axis
-    psum cannot prove the cotangent replicated under ``check_rep=False``
+    psum cannot prove the cotangent replicated under ``check_vma=False``
     and psums it."""
     fwd = matmul_comm_elems(M, C, N, grid)
     replay = 0.0 if save_gathered else 1.0

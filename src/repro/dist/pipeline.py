@@ -32,7 +32,7 @@ from jax import lax
 
 from jax.sharding import PartitionSpec as P
 
-from repro.dist._compat import shard_map
+from jax import shard_map
 from repro.dist.collectives import ppermute, psum
 
 
@@ -103,7 +103,7 @@ def _pipelined(stage_fn, mesh, axis, params, x):
         functools.partial(_pipe_fwd_local, stage_fn, axis, n_stages,
                           n_micro, False),
         mesh=mesh, in_specs=(spec_tree, P()), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(params, x)
 
 
@@ -114,7 +114,7 @@ def _pipelined_fwd(stage_fn, mesh, axis, params, x):
         functools.partial(_pipe_fwd_local, stage_fn, axis, n_stages,
                           n_micro, True),
         mesh=mesh, in_specs=(spec_tree, P()),
-        out_specs=(P(), P(axis)), check_rep=False)
+        out_specs=(P(), P(axis)), check_vma=False)
     out, stash = fn(params, x)
     return out, (params, stash)
 
@@ -127,7 +127,7 @@ def _pipelined_bwd(stage_fn, mesh, axis, res, g):
         functools.partial(_pipe_bwd_local, stage_fn, axis, n_stages,
                           n_micro),
         mesh=mesh, in_specs=(spec_tree, P(axis), P()),
-        out_specs=(spec_tree, P()), check_rep=False)
+        out_specs=(spec_tree, P()), check_vma=False)
     return fn(params, stash, g)
 
 

@@ -10,7 +10,9 @@ distributed schedules pay the tuning cost once per machine:
 * in-memory memo: one timing pass per key per process;
 * on disk: ``.repro_autotune.json`` (override with ``REPRO_AUTOTUNE_CACHE``)
   — reloaded lazily, written atomically after each new measurement, and
-  machine-specific (wall-clock winners), so it is *not* checked in;
+  machine-specific (wall-clock winners), so it is *not* checked in.  Every
+  key ends in the device kind (``kernels.ops.device_kind``), so a table
+  timed on one device kind is never read on another;
 * ``REPRO_AUTOTUNE`` env control: ``1`` (default) tunes, ``0`` disables
   the tuner entirely (callers fall back to their static paper-plan
   dispatch), ``refresh`` ignores persisted winners and re-times each key
@@ -75,7 +77,9 @@ def autotune_disabled():
 class PlanCache:
     """Winner-per-key table with lazy JSON load and atomic save.
 
-    Entries: ``{key: {"impl": name, "wall_ms": {candidate: ms}}}``."""
+    Entries: ``{key: {"impl": name, "wall_ms": {candidate: ms},
+    "refused": {candidate: error}}}`` (``refused`` only when a candidate
+    failed)."""
 
     def __init__(self, path: Optional[str] = None):
         self._path_override = path
@@ -109,9 +113,11 @@ class PlanCache:
             self._load()
         return self._mem.get(key)
 
-    def record(self, key: str, impl: str,
-               wall_ms: Dict[str, float]) -> None:
+    def record(self, key: str, impl: str, wall_ms: Dict[str, float],
+               refused: Optional[Dict[str, str]] = None) -> None:
         self._mem[key] = {"impl": impl, "wall_ms": wall_ms}
+        if refused:
+            self._mem[key]["refused"] = dict(refused)
         self.save()
 
     def save(self) -> None:
@@ -146,9 +152,11 @@ def plan_cache() -> PlanCache:
 # Timing
 # --------------------------------------------------------------------------
 
-def _time_ms(fn: Callable, args: tuple, *, reps: int) -> float:
-    """min-of-``reps`` wall ms of the jitted candidate (one warmup call
-    pages everything in); ``inf`` when the candidate fails.
+def _time_ms(fn: Callable, args: tuple, *, reps: int
+             ) -> Tuple[float, Optional[str]]:
+    """``(ms, None)``: min-of-``reps`` wall ms of the jitted candidate (one
+    warmup call pages everything in); ``(inf, error)`` when the candidate
+    fails to compile or run.
 
     Compiled ahead of time (``jit(fn).lower(...).compile()``): dispatch
     happens at trace time, so a timing pass is often reached while an
@@ -163,9 +171,10 @@ def _time_ms(fn: Callable, args: tuple, *, reps: int) -> float:
             t0 = time.perf_counter()
             jfn(*args).block_until_ready()
             best = min(best, (time.perf_counter() - t0) * 1e3)
-        return best
-    except Exception:
-        return float("inf")
+        return best, None
+    except Exception as e:  # noqa: BLE001 — recorded, never silent
+        msg = str(e).strip().splitlines()
+        return float("inf"), f"{type(e).__name__}: {msg[0] if msg else ''}"
 
 
 def best_of(key: str, candidates: Sequence[Tuple[str, Callable]],
@@ -173,24 +182,33 @@ def best_of(key: str, candidates: Sequence[Tuple[str, Callable]],
     """Winning implementation name for ``key``.
 
     ``candidates`` is an ordered ``(name, fn)`` menu (first entry wins
-    ties and is the fallback when every candidate fails); ``make_args``
-    draws the concrete operands the timing pass runs on.  The winner is
-    memoized in the process-wide :class:`PlanCache` and persisted."""
+    ties); ``make_args`` draws the concrete operands the timing pass runs
+    on.  The winner is memoized in the process-wide :class:`PlanCache`
+    and persisted; a candidate that failed is kept in the entry's
+    ``refused`` map with its error.  When every candidate fails, the CPU
+    falls back to the first (interpret-mode kernels under an outer trace
+    can fail to time); any other backend raises."""
     names = [n for n, _ in candidates]
     if len(names) == 1:
         return names[0]
     ent = _cache.lookup(key, allow_file=mode() != "refresh")
     if ent and ent.get("impl") in names:
         return ent["impl"]
-    args = make_args()
-    wall_ms = {name: _time_ms(fn, args, reps=reps)
-               for name, fn in candidates}
-    if all(t == float("inf") for t in wall_ms.values()):
-        # timing impossible here (every candidate failed): fall back to
-        # the static choice and leave the key untuned for a later pass
+    # dispatch runs while an outer jit traces; without this scope the
+    # operands drawn here would be that trace's tracers, and no candidate
+    # could run on them
+    with jax.ensure_compile_time_eval():
+        args = make_args()
+    timed = {name: _time_ms(fn, args, reps=reps) for name, fn in candidates}
+    wall_ms = {name: t for name, (t, _) in timed.items()}
+    refused = {name: err for name, (_, err) in timed.items() if err}
+    if len(refused) == len(names):
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(f"every candidate for {key} failed: {refused}")
+        # leave the key untuned for a later pass
         return names[0]
     impl = min(names, key=lambda n: wall_ms[n])  # first-listed wins ties
-    _cache.record(key, impl, wall_ms)
+    _cache.record(key, impl, wall_ms, refused)
     return impl
 
 

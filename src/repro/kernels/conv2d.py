@@ -1,19 +1,22 @@
-"""Pallas TPU direct-conv kernel (stride-1 SAME, NCHW).
+"""Pallas TPU direct-conv kernel (stride 1, NCHW in and out).
 
 TPU-native realization of the paper's tiled CNN (Listing 3) at the
 HBM->VMEM level: grid (i, j, q) over (batch tiles, k tiles, c slabs); the
 Out tile stays resident in a VMEM f32 scratch across the sequential c slabs
 (the paper's "store Out once" schedule), while In/Ker tiles stream in.
 
-The stencil is reassociated into ``kh*kw`` MXU matmuls of shape
-``(Tb*H*W, Tc) @ (Tc, Tk)`` — shifted-window slices of the padded input
-against the (r, s) slice of the kernel — so the systolic array sees a
-contraction dim of Tc (>=128 where possible; see kernels/tiling.py for why
-we deviate from the paper's T_c = 1 on TPU).
+Inside the kernel the operands are channels-last (the wrapper transposes
+NCHW -> NHWC and OIHW -> HWIO around the call), so channels ride the
+128-lane axis and the image width the sublanes.  Each output row is
+``kh*kw`` MXU matmuls of shape ``(Wo, Tc) @ (Tc, Tk)`` — the shifted
+input row against the (r, s) slice of the kernel — accumulated in f32.
+Keeping every in-kernel access a plain row load avoids the minor-dim
+transposes and reshapes the TPU compiler cannot lower cheaply.
 
-Spatial dims stay whole inside the block (DL feature maps at these sizes
-fit VMEM comfortably; blocking h/w would need overlapping halo reads that
-Pallas blocked indexing cannot express).
+Spatial dims stay whole inside the block (blocking h/w would need
+overlapping halo reads that Pallas blocked indexing cannot express), so
+the padded image slab of one block must fit VMEM: ``kernels.tiling``
+``fit_conv_blocks`` decides which shapes the kernel can hold.
 """
 
 from __future__ import annotations
@@ -22,33 +25,38 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
 def _conv_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_c: int, kh: int, kw: int):
+    # x_ref [Tb,Hp,Wp,Tc]  w_ref [kh,kw,Tc,Tk]  o_ref/acc_ref [Tb,Ho,Wo,Tk]
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    tb, tc, hp, wp = x_ref.shape
-    tk = w_ref.shape[0]
-    h, w = hp - kh + 1, wp - kw + 1
-    acc = jnp.zeros((tb * h * w, tk), jnp.float32)
-    for r in range(kh):
-        for s in range(kw):
-            patch = x_ref[:, :, r:r + h, s:s + w]            # [Tb,Tc,H,W]
-            lhs = patch.transpose(0, 2, 3, 1).reshape(tb * h * w, tc)
-            rhs = w_ref[:, :, r, s].transpose(1, 0)          # [Tc,Tk]
-            acc += jnp.dot(lhs, rhs, preferred_element_type=jnp.float32)
-    acc_ref[...] += acc.reshape(tb, h, w, tk).transpose(0, 3, 1, 2)
+    tb, ho, wo, tk = acc_ref.shape
+
+    def out_row(t, carry):
+        b, i = t // ho, t % ho
+        acc = jnp.zeros((wo, tk), jnp.float32)
+        for r in range(kh):
+            for s in range(kw):
+                lhs = x_ref[b, i + r, pl.ds(s, wo), :]        # [Wo,Tc]
+                acc += jnp.dot(lhs, w_ref[r, s],
+                               preferred_element_type=jnp.float32)
+        acc_ref[b, i] += acc
+        return carry
+
+    lax.fori_loop(0, tb * ho, out_row, 0)
 
     @pl.when(pl.program_id(2) == n_c - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def conv2d_pallas(x: jax.Array, w: jax.Array, *, block_b: int = 8,
+def conv2d_pallas(x: jax.Array, w: jax.Array, *, block_b: int = 1,
                   block_k: int = 128, block_c: int = 128,
                   padding: str = "SAME",
                   interpret: bool = False) -> jax.Array:
@@ -74,15 +82,16 @@ def conv2d_pallas(x: jax.Array, w: jax.Array, *, block_b: int = 8,
     hp, wp = xp.shape[2], xp.shape[3]
     ho, wo = hp - kh + 1, wp - kw + 1
     n_c = c // bc
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_conv_kernel, n_c=n_c, kh=kh, kw=kw),
         grid=(n // bb, k // bk, n_c),
         in_specs=[
-            pl.BlockSpec((bb, bc, hp, wp), lambda i, j, q: (i, q, 0, 0)),
-            pl.BlockSpec((bk, bc, kh, kw), lambda i, j, q: (j, q, 0, 0)),
+            pl.BlockSpec((bb, hp, wp, bc), lambda i, j, q: (i, 0, 0, q)),
+            pl.BlockSpec((kh, kw, bc, bk), lambda i, j, q: (0, 0, q, j)),
         ],
-        out_specs=pl.BlockSpec((bb, bk, ho, wo), lambda i, j, q: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, k, ho, wo), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bb, bk, ho, wo), jnp.float32)],
+        out_specs=pl.BlockSpec((bb, ho, wo, bk), lambda i, j, q: (i, 0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((n, ho, wo, k), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bb, ho, wo, bk), jnp.float32)],
         interpret=interpret,
-    )(xp, w)
+    )(xp.transpose(0, 2, 3, 1), w.transpose(2, 3, 1, 0))
+    return out.transpose(0, 3, 1, 2)

@@ -14,12 +14,13 @@ candidate once, memoizes the winner, and persists the plan table to
 The conv candidate menu:
 
 * ``direct``   — ``kernels.conv2d.conv2d_pallas``, the paper's two-level
-  tiled direct conv (stride 1, feature dims that tile into >= 8 blocks);
+  tiled direct conv (stride 1, feature dims that tile into >= 8 blocks,
+  an image slab that fits VMEM);
 * ``winograd`` — ``kernels.winograd.conv2d_winograd``, F(2x2,3x3)
   transforms around a batched 16-frequency tile GEMM (3x3 stride-1, the
   CNN FLOPs hot spot; 2.25x fewer multiplies);
 * ``im2col``   — ``kernels.gemm_conv.conv2d_im2col``, the patch-matrix
-  GEMM (any stride, any extent — the universal candidate);
+  GEMM (any stride, any extent; stencils up to ``MAX_STENCIL_TAPS``);
 * ``xla``      — ``lax.conv_general_dilated``.
 
 The matmul menu is ``pallas`` (tiled ``matmul_pallas`` with the memoized
@@ -34,18 +35,22 @@ dIn/dKer of a stride-1 conv are convs), so every candidate — and hence
 every winner — differentiates natively; the dist ``save_gathered=True``
 paths no longer force the XLA fallback.
 
-Block shapes still come from the paper-derived plan (`kernels.tiling`),
-memoized per shape (`matmul_plan` / `conv_plan`).  On CPU (this
-container) the Pallas kernels execute in interpret mode; on TPU they
-compile to Mosaic.  ``REPRO_DIST_PALLAS=0`` removes the Pallas
-candidates everywhere; ``REPRO_AUTOTUNE=0`` disables the tuner and
-restores the static paper-plan dispatch (see ``kernels.autotune``).
+Block shapes come from the paper-derived plan (`kernels.tiling`), fitted
+to the chip's (sublane, 128) tiling and VMEM budget and memoized per shape
+(`matmul_plan` / `conv_plan`).  A shape no block fits loses its Pallas
+candidate before anything is compiled; :func:`matmul_exclusion` /
+:func:`conv_exclusion` name the rule, and :func:`dispatch_table` lists
+every key with its winner, the excluded Pallas candidate and any
+candidate that failed while timed.  On the CPU the Pallas kernels execute
+in interpret mode; on TPU they compile to Mosaic.
+``REPRO_DIST_PALLAS=0`` removes the Pallas candidates everywhere;
+``REPRO_AUTOTUNE=0`` disables the tuner and restores the static
+paper-plan dispatch (see ``kernels.autotune``).
 """
 
 from __future__ import annotations
 
 import functools
-import math
 import os
 
 import jax
@@ -57,6 +62,7 @@ from repro.kernels import autotune, tiling
 from repro.kernels.conv2d import conv2d_pallas
 from repro.kernels.gemm_conv import conv2d_im2col
 from repro.kernels.matmul import matmul_pallas
+from repro.kernels.tiling import math_gcd_block  # noqa: F401 (re-export)
 from repro.kernels.winograd import (conv2d_winograd, winograd_applicable,
                                     wino_gemm_einsum, wino_gemm_pallas)
 
@@ -72,47 +78,33 @@ def _pallas_enabled() -> bool:
     return os.environ.get(_DIST_PALLAS_ENV, "1") != "0"
 
 
-@functools.lru_cache(maxsize=None)
-def math_gcd_block(extent: int, want: int) -> int:
-    """Largest divisor of ``extent`` not exceeding ``want`` — by divisor
-    enumeration in O(sqrt(extent)) (the former descending scan was
-    O(extent) on large prime extents), memoized alongside the plans."""
-    want = min(want, extent)
-    best = 1
-    for d in range(1, math.isqrt(extent) + 1):
-        if extent % d == 0:
-            if d <= want:
-                best = max(best, d)
-            q = extent // d
-            if q <= want:
-                best = max(best, q)
-    return best
-
-
 # --------------------------------------------------------------------------
 # Memoized tiling plans (the Eq. 4 solve is pure Python; one per shape)
 # --------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=None)
-def matmul_plan(m: int, n: int, k: int):
+def matmul_plan(m: int, n: int, k: int, itemsize: int = 4):
     """Paper-planned ``(block_m, block_n, block_k)`` for an ``[m,k]@[k,n]``
-    matmul, projected onto exact divisors of the extents."""
-    bm, bn, bk = tiling.matmul_blocks(m, n, k)
-    return (bm if m % bm == 0 else math_gcd_block(m, bm),
-            bn if n % bn == 0 else math_gcd_block(n, bn),
-            bk if k % bk == 0 else math_gcd_block(k, bk))
+    matmul of ``itemsize``-byte elements, fitted to the chip's tiling and
+    VMEM budget (``tiling.fit_matmul_blocks``); ``None`` when no block
+    fits."""
+    return tiling.fit_matmul_blocks(m, n, k, tiling.matmul_blocks(m, n, k),
+                                    itemsize)
 
 
 @functools.lru_cache(maxsize=None)
-def conv_plan(n: int, c: int, k: int, h: int, w: int, kh: int, kw: int):
-    """Paper-planned ``(block_b, block_k, block_c)`` for an NCHW/OIHW conv,
-    projected onto exact divisors."""
+def conv_plan(n: int, c: int, k: int, h: int, w: int, kh: int, kw: int,
+              itemsize: int = 4, padding: str = "VALID"):
+    """Paper-planned ``(block_b, block_k, block_c)`` for an NCHW/OIHW
+    stride-1 conv of an ``h x w`` input, fitted to the chip's tiling and
+    VMEM budget (``tiling.fit_conv_blocks``); ``None`` when even the
+    narrowest legal block does not fit."""
     prob = ConvProblem.from_conv_layer(batch=n, cin=c, cout=k, h=h, w=w,
                                        kh=kh, kw=kw)
     plan = tiling.plan_blocks(prob)
-    return (math_gcd_block(n, max(1, plan.block_bhw // (h * w))),
-            math_gcd_block(k, plan.block_k),
-            math_gcd_block(c, plan.block_c))
+    want = (max(1, plan.block_bhw // (h * w)), plan.block_k, plan.block_c)
+    hp, wp = (h + kh - 1, w + kw - 1) if padding == "SAME" else (h, w)
+    return tiling.fit_conv_blocks(n, c, k, hp, wp, kh, kw, want, itemsize)
 
 
 # --------------------------------------------------------------------------
@@ -210,10 +202,8 @@ def matmul(x: jax.Array, w: jax.Array, *, block_m: int = 0, block_n: int = 0,
            block_k: int = 0) -> jax.Array:
     """Paper-planned tiled matmul.  Shapes must divide by the chosen blocks
     (the planner only returns divisors of MXU-aligned extents)."""
-    m, k = x.shape
-    _, n = w.shape
     if not (block_m and block_n and block_k):
-        block_m, block_n, block_k = matmul_plan(m, n, k)
+        block_m, block_n, block_k = _matmul_blocks(x, w)
     return _matmul_pallas_vjp(x, w, (block_m, block_n, block_k))
 
 
@@ -228,10 +218,8 @@ def conv2d_same(x: jax.Array, w: jax.Array, *, block_b: int = 0,
         return lax.conv_general_dilated(
             x, w, (1, 1), "SAME", dimension_numbers=_DIMNUMS,
             preferred_element_type=jnp.float32).astype(x.dtype)
-    n, c, h, wd = x.shape
-    k, _, kh, kw = w.shape
     if not (block_b and block_k and block_c):
-        block_b, block_k, block_c = conv_plan(n, c, k, h, wd, kh, kw)
+        block_b, block_k, block_c = _conv_blocks(x, w, "SAME")
     return _conv_pallas_vjp(x, w, (block_b, block_k, block_c), "SAME")
 
 
@@ -239,25 +227,85 @@ def conv2d_same(x: jax.Array, w: jax.Array, *, block_b: int = 0,
 # Applicability predicates
 # --------------------------------------------------------------------------
 
-def pallas_applicable_matmul(m: int, n: int, k: int) -> bool:
-    """The Pallas matmul covers the shape when every extent tiles into
-    blocks of at least the VPU sublane width (8)."""
-    return m % 8 == 0 and n % 8 == 0 and k % 8 == 0
+# Largest stencil the direct kernel and im2col unroll (kh*kw MXU dots per
+# output row; kh*kw patch slices): 7x7, the ResNet stem.  Larger
+# "kernels" — the dKer correlation, whose stencil is the whole image —
+# belong to XLA.
+MAX_STENCIL_TAPS = 49
 
 
-def pallas_applicable_conv(x_shape, w_shape, stride, padding) -> bool:
-    """The Pallas direct conv covers stride-1 SAME/VALID with feature dims
-    that tile into >= 8-wide blocks and kernels no larger than the image."""
+def _fitted(blocks, *operands):
+    """The plan's blocks, or ``ValueError`` for a shape no block fits (the
+    dispatchers exclude such shapes before they get here)."""
+    if blocks is None:
+        raise ValueError("no Pallas block fits " + " x ".join(
+            f"{o.shape}{o.dtype}" for o in operands))
+    return blocks
+
+
+def _matmul_blocks(x, w):
+    return _fitted(matmul_plan(x.shape[0], w.shape[1], x.shape[1],
+                               x.dtype.itemsize), x, w)
+
+
+def _conv_blocks(x, w, padding):
+    n, c, h, wd = x.shape
+    k, _, kh, kw = w.shape
+    return _fitted(conv_plan(n, c, k, h, wd, kh, kw, x.dtype.itemsize,
+                             padding), x, w)
+
+
+def _wino_gemm_blocks(v, u):
+    return _fitted(matmul_plan(v.shape[1], u.shape[2], v.shape[2],
+                               v.dtype.itemsize), v, u)
+
+
+def matmul_exclusion(m: int, n: int, k: int, dtype=jnp.float32):
+    """``None`` when the Pallas matmul covers the shape, else the rule that
+    excludes it: feature extents that do not tile into blocks of the VPU
+    sublane width (8), rows that neither do so nor fit one block of fewer
+    than 8 (a decode batch), or no (sublane, 128)-tiled block that fits
+    VMEM."""
+    if n % 8 or k % 8 or (m % 8 and m > 8):
+        return "extent % 8 != 0"
+    if matmul_plan(m, n, k, jnp.dtype(dtype).itemsize) is None:
+        return "no tiled block fits VMEM"
+    return None
+
+
+def conv_exclusion(x_shape, w_shape, stride, padding, dtype=jnp.float32):
+    """``None`` when the direct Pallas conv covers the shape, else the rule
+    that excludes it."""
     n, c, h, wd = x_shape
     k, c2, kh, kw = w_shape
-    return (tuple(stride) == (1, 1) and padding in ("SAME", "VALID")
-            and c == c2 and k % 8 == 0 and c % 8 == 0
-            and kh <= h and kw <= wd)
+    if tuple(stride) != (1, 1):
+        return "stride != 1"
+    if padding not in ("SAME", "VALID") or c != c2:
+        return "padding/channels"
+    if k % 8 or c % 8:
+        return "C or K % 8 != 0"
+    if kh > h or kw > wd:
+        return "kernel larger than image"
+    if kh * kw > MAX_STENCIL_TAPS:
+        return f"stencil {kh}x{kw} > {MAX_STENCIL_TAPS} taps"
+    if conv_plan(n, c, k, h, wd, kh, kw, jnp.dtype(dtype).itemsize,
+                 padding) is None:
+        return "image slab does not fit VMEM"
+    return None
 
 
-def wino_gemm_applicable(p: int, k: int, c: int) -> bool:
-    """The Pallas batched tile GEMM tiles like the matmul kernel."""
-    return pallas_applicable_matmul(p, k, c)
+def pallas_applicable_matmul(m: int, n: int, k: int,
+                             dtype=jnp.float32) -> bool:
+    """The Pallas matmul covers the shape (see :func:`matmul_exclusion`)."""
+    return matmul_exclusion(m, n, k, dtype) is None
+
+
+def pallas_applicable_conv(x_shape, w_shape, stride, padding,
+                           dtype=jnp.float32) -> bool:
+    """The Pallas direct conv covers stride-1 SAME/VALID with feature dims
+    that tile into >= 8-wide blocks, kernels no larger than the image, and
+    a block that fits VMEM (see :func:`conv_exclusion`)."""
+    return conv_exclusion(x_shape, w_shape, stride, padding, dtype) is None
 
 
 # --------------------------------------------------------------------------
@@ -268,29 +316,65 @@ def _dt(dtype) -> str:
     return jnp.dtype(dtype).name
 
 
+@functools.lru_cache(maxsize=None)
+def device_kind() -> str:
+    """Kind of the default device (``"cpu"``, ``"TPU v5 lite"``, ...).
+    Every plan-table key ends in it, so a winner timed on one device kind
+    is never read on another."""
+    return jax.devices()[0].device_kind
+
+
 def conv_key(x_shape, w_shape, dtype, stride, padding) -> str:
     n, c, h, wd = x_shape
     k, _, kh, kw = w_shape
     return (f"conv2d:{n}x{c}x{h}x{wd}:k{k}:{kh}x{kw}"
-            f":s{stride[0]}x{stride[1]}:{padding}:{_dt(dtype)}")
+            f":s{stride[0]}x{stride[1]}:{padding}:{_dt(dtype)}"
+            f"@{device_kind()}")
 
 
 def matmul_key(m: int, n: int, k: int, dtype) -> str:
-    return f"matmul:{m}x{k}x{n}:{_dt(dtype)}"
+    return f"matmul:{m}x{k}x{n}:{_dt(dtype)}@{device_kind()}"
 
 
 def wino_gemm_key(p: int, k: int, c: int, dtype) -> str:
-    return f"wino_gemm:16x{p}x{c}:k{k}:{_dt(dtype)}"
+    return f"wino_gemm:16x{p}x{c}:k{k}:{_dt(dtype)}@{device_kind()}"
 
 
 def _rand(shape, dtype):
     return jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
 
 
+# Every local-kernel key this process dispatched (see dispatch_table).
+_DISPATCH: dict = {}
+
+
+def dispatch_table() -> dict:
+    """``{key: {"impl", "excluded", "refused"}}`` for every local-kernel
+    key dispatched in this process: the implementation chosen, the Pallas
+    candidate a shape rule removed before any compile (with the rule), and
+    the candidates that failed to compile or run while being timed (with
+    the error)."""
+    return {k: dict(v) for k, v in _DISPATCH.items()}
+
+
+def _note(key: str, impl: str, excluded: dict) -> None:
+    ent = autotune.plan_cache().lookup(key, allow_file=False) or {}
+    _DISPATCH[key] = {"impl": impl, "excluded": dict(excluded),
+                      "refused": dict(ent.get("refused", {}))}
+
+
+def _pallas_gate(allow_pallas: bool, rule):
+    """``None`` when the Pallas candidate may run, else why it may not."""
+    if not allow_pallas:
+        return "prefer_pallas=False"
+    if not _pallas_enabled():
+        return f"{_DIST_PALLAS_ENV}=0"
+    return rule()
+
+
 def _run_matmul_impl(impl: str, x, w):
     if impl == "pallas":
-        return _matmul_pallas_vjp(x, w, matmul_plan(x.shape[0], w.shape[1],
-                                                    x.shape[1]))
+        return _matmul_pallas_vjp(x, w, _matmul_blocks(x, w))
     return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(
         jnp.result_type(x.dtype, w.dtype))
 
@@ -298,26 +382,26 @@ def _run_matmul_impl(impl: str, x, w):
 def select_matmul_impl(m: int, n: int, k: int, dtype, *,
                        allow_pallas: bool = True) -> str:
     """Winning matmul impl (``pallas`` | ``xla``) for the shape — the
-    static paper plan (Pallas when the shape tiles) when the autotuner is
-    off, the timed best-of otherwise."""
-    pallas_ok = (allow_pallas and _pallas_enabled()
-                 and pallas_applicable_matmul(m, n, k))
-    if not pallas_ok:
-        return "xla"
-    if not autotune.enabled():
-        return "pallas"
-    cands = [("pallas", functools.partial(_run_matmul_impl, "pallas")),
-             ("xla", functools.partial(_run_matmul_impl, "xla"))]
-    return autotune.best_of(
-        matmul_key(m, n, k, dtype), cands,
-        lambda: (_rand((m, k), dtype), _rand((k, n), dtype)))
+    static paper plan (Pallas when the shape tiles and fits) when the
+    autotuner is off, the timed best-of otherwise."""
+    key = matmul_key(m, n, k, dtype)
+    why = _pallas_gate(allow_pallas, lambda: matmul_exclusion(m, n, k, dtype))
+    if why is not None:
+        impl = "xla"
+    elif not autotune.enabled():
+        impl = "pallas"
+    else:
+        cands = [("pallas", functools.partial(_run_matmul_impl, "pallas")),
+                 ("xla", functools.partial(_run_matmul_impl, "xla"))]
+        impl = autotune.best_of(
+            key, cands, lambda: (_rand((m, k), dtype), _rand((k, n), dtype)))
+    _note(key, impl, {"pallas": why} if why else {})
+    return impl
 
 
 def _run_wino_gemm_impl(impl: str, v, u):
     if impl == "pallas":
-        t, p, c = v.shape
-        k = u.shape[2]
-        return _wino_gemm_pallas_vjp(v, u, matmul_plan(p, k, c))
+        return _wino_gemm_pallas_vjp(v, u, _wino_gemm_blocks(v, u))
     return wino_gemm_einsum(v, u)
 
 
@@ -327,8 +411,9 @@ def wino_gemm(v: jax.Array, u: jax.Array) -> jax.Array:
     otherwise."""
     t, p, c = v.shape
     k = u.shape[2]
-    pallas_ok = _pallas_enabled() and wino_gemm_applicable(p, k, c)
-    if not pallas_ok:
+    key = wino_gemm_key(p, k, c, v.dtype)
+    why = _pallas_gate(True, lambda: matmul_exclusion(p, k, c, v.dtype))
+    if why is not None:
         impl = "einsum"
     elif not autotune.enabled():
         impl = "pallas"
@@ -336,17 +421,15 @@ def wino_gemm(v: jax.Array, u: jax.Array) -> jax.Array:
         cands = [("pallas", functools.partial(_run_wino_gemm_impl, "pallas")),
                  ("einsum", functools.partial(_run_wino_gemm_impl, "einsum"))]
         impl = autotune.best_of(
-            wino_gemm_key(p, k, c, v.dtype), cands,
+            key, cands,
             lambda: (_rand(v.shape, v.dtype), _rand(u.shape, u.dtype)))
+    _note(key, impl, {"pallas": why} if why else {})
     return _run_wino_gemm_impl(impl, v, u)
 
 
 def _run_conv_impl(impl: str, x, w, stride, padding):
     if impl == "direct":
-        n, c, h, wd = x.shape
-        k, _, kh, kw = w.shape
-        return _conv_pallas_vjp(x, w, conv_plan(n, c, k, h, wd, kh, kw),
-                                padding)
+        return _conv_pallas_vjp(x, w, _conv_blocks(x, w, padding), padding)
     if impl == "winograd":
         return conv2d_winograd(x, w, padding=padding, gemm=wino_gemm)
     if impl == "im2col":
@@ -358,17 +441,23 @@ def _run_conv_impl(impl: str, x, w, stride, padding):
             jnp.result_type(x.dtype, w.dtype))
 
 
+def _direct_gate(x_shape, w_shape, stride, padding, dtype, allow_pallas):
+    return _pallas_gate(allow_pallas, lambda: conv_exclusion(
+        x_shape, w_shape, stride, padding, dtype))
+
+
 def conv_candidates(x_shape, w_shape, stride, padding, *,
-                    allow_pallas: bool = True) -> list:
+                    allow_pallas: bool = True,
+                    dtype=jnp.float32) -> list:
     """Ordered applicable-candidate names for the conv shape (the static
     paper-plan choice first)."""
-    direct_ok = (allow_pallas and _pallas_enabled()
-                 and pallas_applicable_conv(x_shape, w_shape, stride,
-                                            padding))
+    direct_ok = _direct_gate(x_shape, w_shape, stride, padding, dtype,
+                             allow_pallas) is None
     cands = ["direct"] if direct_ok else []
     if winograd_applicable(x_shape, w_shape, stride, padding):
         cands.append("winograd")
-    cands.append("im2col")
+    if w_shape[2] * w_shape[3] <= MAX_STENCIL_TAPS:
+        cands.append("im2col")
     cands.append("xla")
     if not direct_ok:  # static choice (xla) leads when direct is out
         cands.remove("xla")
@@ -382,16 +471,21 @@ def select_conv_impl(x_shape, w_shape, dtype, stride, padding, *,
     ``xla``) for the shape — the static paper plan when the autotuner is
     off, the timed best-of otherwise."""
     stride = tuple(stride)
+    key = conv_key(x_shape, w_shape, dtype, stride, padding)
+    why = _direct_gate(x_shape, w_shape, stride, padding, dtype,
+                       allow_pallas)
     cands = conv_candidates(x_shape, w_shape, stride, padding,
-                            allow_pallas=allow_pallas)
+                            allow_pallas=allow_pallas, dtype=dtype)
     if not autotune.enabled():
-        return cands[0]  # static paper plan: direct when it tiles, else xla
-    menu = [(name, functools.partial(_run_conv_impl, name, stride=stride,
-                                     padding=padding))
-            for name in cands]
-    return autotune.best_of(
-        conv_key(x_shape, w_shape, dtype, stride, padding), menu,
-        lambda: (_rand(x_shape, dtype), _rand(w_shape, dtype)))
+        impl = cands[0]  # static paper plan: direct when it fits, else xla
+    else:
+        menu = [(name, functools.partial(_run_conv_impl, name,
+                                         stride=stride, padding=padding))
+                for name in cands]
+        impl = autotune.best_of(
+            key, menu, lambda: (_rand(x_shape, dtype), _rand(w_shape, dtype)))
+    _note(key, impl, {"direct": why} if why else {})
+    return impl
 
 
 # --------------------------------------------------------------------------

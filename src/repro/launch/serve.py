@@ -68,6 +68,7 @@ class Request:
     status: str = "ok"
     error: str = ""
     t_submit: float = 0.0
+    prefill_logits: Optional[object] = None
 
 
 class ContinuousEngine:
@@ -85,7 +86,7 @@ class ContinuousEngine:
                  max_queue: Optional[int] = None,
                  decode_watchdog_timeout_s: Optional[float] = None,
                  state_dump_path: Optional[str] = None,
-                 fault_log=None, injector=None):
+                 fault_log=None, injector=None, keep_logits: bool = False):
         import jax
         import jax.numpy as jnp
 
@@ -107,6 +108,7 @@ class ContinuousEngine:
         self.state_dump_path = state_dump_path
         self.fault_log = fault_log
         self.injector = injector
+        self.keep_logits = keep_logits
         self.queue: deque = deque()
         self.active: List[Optional[Request]] = [None] * slots
         self.retired: List[Request] = []
@@ -219,6 +221,8 @@ class ContinuousEngine:
             logits, stage = self._prefill_fn(self.params, toks, plen - 1)
             first = int(logits[0, 0].argmax())
             req.prefill_ms = (time.perf_counter() - t0) * 1e3
+            if self.keep_logits:
+                req.prefill_logits = logits[0, 0]
             self.cache["k"] = self.cache["k"].at[:, slot].set(
                 stage["k"][:, 0])
             self.cache["v"] = self.cache["v"].at[:, slot].set(
@@ -456,14 +460,17 @@ def run(cfg, *, requests: int = 8, prompt_len: int = 16, gen: int = 16,
         warmup: bool = False, max_queue: Optional[int] = None,
         deadline_s: Optional[float] = None,
         decode_watchdog_timeout_s: Optional[float] = None,
-        state_dump_path: Optional[str] = None) -> Dict:
+        state_dump_path: Optional[str] = None,
+        keep_logits: bool = False) -> Dict:
     """Serve a deterministic request set; the callable engine API.
 
     ``grid``: a ``(Pm, Pn, Pc)`` tuple, ``"auto"`` (synthesized over all
     visible devices via ``synthesize_serve_grid``), or ``None`` (dense).
     ``max_queue`` / ``deadline_s`` / ``decode_watchdog_timeout_s`` are
     the degradation knobs (backpressure, per-request deadlines, wedge
-    state dump — see ``docs/fault.md``).  Returns the stats dict of
+    state dump — see ``docs/fault.md``).  ``keep_logits`` adds
+    ``prefill_logits``: each request's last-prompt-position logits
+    ``[vocab]`` (f32, on the device).  Returns the stats dict of
     :meth:`ContinuousEngine.serve` plus the grid/schedule and the
     analytic wire/memory accounting.
     """
@@ -492,7 +499,7 @@ def run(cfg, *, requests: int = 8, prompt_len: int = 16, gen: int = 16,
         dist_schedule=schedule, prefill_bucket=prefill_bucket,
         max_queue=max_queue,
         decode_watchdog_timeout_s=decode_watchdog_timeout_s,
-        state_dump_path=state_dump_path)
+        state_dump_path=state_dump_path, keep_logits=keep_logits)
     reqs = _make_requests(cfg, requests=requests, prompt_len=prompt_len,
                           gen=gen, seed=seed, deadline_s=deadline_s)
     if warmup:
@@ -501,6 +508,10 @@ def run(cfg, *, requests: int = 8, prompt_len: int = 16, gen: int = 16,
     res["arch"] = cfg.arch_id
     res["grid"] = tuple(grid) if grid is not None else None
     res["schedule"] = schedule
+    if keep_logits:
+        res["prefill_logits"] = {r.rid: r.prefill_logits
+                                 for r in engine.retired
+                                 if r.prefill_logits is not None}
     if grid is not None:
         from repro.dist.lm import lm_serve_comm_elems, lm_serve_mem_elems
         itemsize = cfg.jdtype.itemsize
@@ -539,6 +550,8 @@ def main(argv=None):
         os.environ.setdefault("XLA_FLAGS",
                               "--xla_force_host_platform_device_count=8")
         os.environ.setdefault("REPRO_DIST_PALLAS", "0")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.smoke and cfg.family in _TRANSFORMER_FAMILIES:
         # greedy token comparison needs f32 headroom, not bf16 rounding

@@ -35,6 +35,7 @@ from repro.ckpt.checkpointer import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticTokens
 from repro.fault.monitor import EmergencySaver, StragglerMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import model_fns
 from repro.train.optim import AdamW, cosine_schedule
@@ -134,6 +135,7 @@ def main():
                     help="JSON-lines FaultEvent log path")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.mesh == "dist-grid":
         return _main_dist_grid(args)
 

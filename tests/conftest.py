@@ -1,22 +1,12 @@
-"""Shared test helpers: JAX-version tolerance shims.
-
-``jax.sharding.AbstractMesh`` changed its constructor across JAX releases
-(older: a ``shape_tuple`` of ``(name, size)`` pairs; newer: positional
-``axis_sizes, axis_names``).  Tests build abstract meshes through
-:func:`make_abstract_mesh` so they run on either signature.
-"""
+"""Shared test fixtures."""
 
 import jax
 import pytest
 
 
 def make_abstract_mesh(shape=(16, 16), axes=("data", "model")):
-    """AbstractMesh from parallel axis-size and axis-name tuples, on any
-    installed JAX."""
-    try:  # newer JAX: AbstractMesh(axis_sizes, axis_names)
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:  # older JAX: AbstractMesh(((name, size), ...))
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    """AbstractMesh from parallel axis-size and axis-name tuples."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 @pytest.fixture

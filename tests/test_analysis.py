@@ -202,7 +202,7 @@ def test_seeded_deadlock_regression_8dev():
         from jax.sharding import PartitionSpec as P
         from repro.analysis.collect import extract_collectives
         from repro.analysis.lints import errors, lint_deadlock
-        from repro.dist._compat import shard_map
+        from jax import shard_map
         from repro.dist.collectives import (make_mesh, ppermute,
                                             record_collectives)
 
@@ -252,7 +252,7 @@ def test_seeded_footprint_regression_8dev():
         from jax.sharding import PartitionSpec as P
         from repro.analysis.collect import extract_collectives
         from repro.analysis.lints import errors, lint_footprint
-        from repro.dist._compat import shard_map
+        from jax import shard_map
         from repro.dist.collectives import gather_axis, make_mesh
 
         mesh = make_mesh((4,), ("k",))
@@ -262,7 +262,7 @@ def test_seeded_footprint_regression_8dev():
             return gather_axis(x, "k", dim=0, schedule="allgather")
 
         fn = shard_map(body, mesh=mesh, in_specs=P("k"),
-                       out_specs=P(None), check_rep=False)
+                       out_specs=P(None), check_vma=False)
         text = jax.jit(fn).lower(
             jax.ShapeDtypeStruct((8, 64), jnp.float32)).compile().as_text()
         colls = extract_collectives(text, axes)

@@ -320,3 +320,39 @@ def test_bench_autotuned_not_slower_than_paper_plan():
         assert r <= 1.25, (name, r, "autotuned slower than paper plan")
     assert min(r for _, r in ratios) < 1.0, \
         (ratios, "autotuner found no strictly faster impl")
+
+
+def test_failing_candidate_is_recorded_as_refused(tuner):
+    def boom(x):
+        raise RuntimeError("ran out of memory in memory space vmem")
+    autotune.best_of("unit:refused", [("bad", boom), ("ok", lambda x: x)],
+                     lambda: (jnp.ones((4, 4), jnp.float32),))
+    refused = tuner.lookup("unit:refused")["refused"]
+    assert set(refused) == {"bad"} and "vmem" in refused["bad"]
+
+
+def test_compile_cache_placement(monkeypatch):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV, "/elsewhere")
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before   # nothing set
+    monkeypatch.delenv(compile_cache.ENV)
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_best_of_times_candidates_under_an_outer_jit(tuner):
+    """Dispatch happens at trace time: the timing pass must run on concrete
+    operands even while an outer ``jax.jit`` is tracing."""
+    a = jnp.ones((16, 24), jnp.float32)
+    b = jnp.ones((24, 8), jnp.float32)
+    out = jax.jit(kops.local_matmul)(a, b)
+    np.testing.assert_allclose(out, a @ b, rtol=1e-5)
+    ent = tuner.lookup(kops.matmul_key(16, 8, 24, jnp.float32))
+    assert ent is not None and "refused" not in ent, ent
+    assert all(np.isfinite(t) for t in ent["wall_ms"].values()), ent

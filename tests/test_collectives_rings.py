@@ -85,7 +85,7 @@ def test_ring_primitives_odd_sizes_8dev():
     fori_loop path and the (me - t) % g source arithmetic)."""
     run_in_subprocess("""
         from jax.sharding import PartitionSpec as P
-        from repro.dist._compat import shard_map
+        from jax import shard_map
         from repro.dist.collectives import (make_mesh, ring_all_gather,
                                             ring_reduce_scatter,
                                             ring_scatter_reduce)
@@ -97,7 +97,7 @@ def test_ring_primitives_odd_sizes_8dev():
             gathered = shard_map(
                 lambda s: ring_all_gather(s, "r", dim=0),
                 mesh=mesh, in_specs=P("r"), out_specs=P(None),
-                check_rep=False)(x)
+                check_vma=False)(x)
             np.testing.assert_allclose(np.asarray(gathered),
                                        np.asarray(x), rtol=1e-6)
 
@@ -105,7 +105,7 @@ def test_ring_primitives_odd_sizes_8dev():
             scattered = shard_map(
                 lambda _s: ring_reduce_scatter(x, "r", dim=0),
                 mesh=mesh, in_specs=P("r"), out_specs=P("r"),
-                check_rep=False)(x)
+                check_vma=False)(x)
             np.testing.assert_allclose(np.asarray(scattered),
                                        g * np.asarray(x), rtol=1e-5)
 
@@ -117,7 +117,7 @@ def test_ring_primitives_odd_sizes_8dev():
                         x, r * chunk, chunk, axis=0)
                 return ring_scatter_reduce("r", produce)
             tok = shard_map(rs_body, mesh=mesh, in_specs=P("r"),
-                            out_specs=P("r"), check_rep=False)(x)
+                            out_specs=P("r"), check_vma=False)(x)
             np.testing.assert_allclose(np.asarray(tok),
                                        g * np.asarray(x), rtol=1e-5)
         print("ok")
@@ -133,7 +133,7 @@ def test_ring_zip_structure_9dev():
     non-trivial unequal sizes (2 x 3) raise ValueError at trace time."""
     run_in_subprocess("""
         from jax.sharding import PartitionSpec as P
-        from repro.dist._compat import shard_map
+        from jax import shard_map
         from repro.dist.collectives import make_mesh, ring_zip
 
         # --- 3 x 3 lockstep structure.  Shards carry their origin rank
@@ -156,7 +156,7 @@ def test_ring_zip_structure_9dev():
         ind, err = shard_map(
             body, mesh=mesh, in_specs=(P("a"), P("b")),
             out_specs=(P("a", "b", None, None), P("a", "b")),
-            check_rep=False)(xa, xb)
+            check_vma=False)(xa, xb)
         ind, err = np.asarray(ind), np.asarray(err)
         assert err.max() == 0, err  # payloads match reported sources
         for ia in range(3):
@@ -183,7 +183,7 @@ def test_ring_zip_structure_9dev():
 
         out = shard_map(body_mm, mesh=mesh,
                         in_specs=(P("a", None), P("b", None)),
-                        out_specs=P(None, None), check_rep=False)(xam, xbm)
+                        out_specs=P(None, None), check_vma=False)(xam, xbm)
         np.testing.assert_allclose(np.asarray(out), np.asarray(xam @ xbm),
                                    rtol=1e-5)
 
@@ -192,7 +192,7 @@ def test_ring_zip_structure_9dev():
         try:
             shard_map(body_mm, mesh=mesh,
                       in_specs=(P("a", None), P("b", None)),
-                      out_specs=P(None, None), check_rep=False)(
+                      out_specs=P(None, None), check_vma=False)(
                 jnp.zeros((2, 6)), jnp.zeros((6, 2)))
             raise SystemExit("ring_zip accepted a 2 x 3 ring pair")
         except ValueError as e:

@@ -11,7 +11,6 @@ import pytest
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-import repro.dist as dist
 from repro.dist.compress import (_quantize_int8, _topk_mask,
                                  compressed_psum, compressed_psum_tree)
 from repro.dist.conv2d import (_pad_amounts, conv2d_distributed,
@@ -28,14 +27,8 @@ def _mesh1(axis="x"):
 def _run_sharded(f, *args, axis="x"):
     mesh = _mesh1(axis)
     specs = tuple(P(axis) for _ in args)
-    return dist.shard_map(f, mesh=mesh, in_specs=specs,
-                          out_specs=P(axis), check_rep=False)(*args)
-
-
-# ------------------------------------------------------------------ compat
-
-def test_jax_shard_map_alias_installed():
-    assert hasattr(jax, "shard_map")
+    return jax.shard_map(f, mesh=mesh, in_specs=specs,
+                         out_specs=P(axis), check_vma=False)(*args)
 
 
 # -------------------------------------------------------------------- halo
@@ -119,8 +112,8 @@ def test_compressed_psum_error_feedback_converges():
         return compressed_psum(gl, "x", el, k_frac=0.25)
 
     mesh = _mesh1()
-    fn = dist.shard_map(f, mesh=mesh, in_specs=(P("x"), P("x")),
-                        out_specs=(P("x"), P("x")), check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(P("x"), P("x")),
+                       out_specs=(P("x"), P("x")), check_vma=False)
     e = jnp.zeros_like(g)
     applied = jnp.zeros_like(g)
     errs = []
@@ -142,8 +135,8 @@ def test_compressed_psum_preserves_err_dtype():
         return compressed_psum(gl, "x", el)
 
     mesh = _mesh1()
-    fn = dist.shard_map(f, mesh=mesh, in_specs=(P("x"), P("x")),
-                        out_specs=(P("x"), P("x")), check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(P("x"), P("x")),
+                       out_specs=(P("x"), P("x")), check_vma=False)
     out, err = fn(g, jnp.zeros_like(g))
     assert err.dtype == jnp.bfloat16
     assert out.dtype == jnp.bfloat16
@@ -160,8 +153,8 @@ def test_compressed_psum_tree_shapes_and_none_err():
 
     mesh = _mesh1()
     spec = jax.tree.map(lambda _: P(), grads)
-    fn = dist.shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                        check_rep=False)
+    fn = jax.shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                       check_vma=False)
     out = fn(grads)
     assert jax.tree.structure(out) == jax.tree.structure(grads)
     # int8 round-trip of a constant tensor is exact (max|v| maps to 127)
@@ -179,8 +172,8 @@ def test_compressed_psum_tree_handles_tuple_pytrees():
 
     mesh = _mesh1()
     spec = jax.tree.map(lambda _: P(), grads)
-    out = dist.shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                         check_rep=False)(grads)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                        check_vma=False)(grads)
     assert jax.tree.structure(out) == jax.tree.structure(grads)
     np.testing.assert_allclose(out[1]["w"][0], grads[1]["w"][0], atol=1e-6)
 

@@ -20,7 +20,6 @@ import pytest
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-import repro.dist as dist
 from repro.core import cost_model
 from repro.core.grid import grid_from_tuple
 from repro.core.problem import ConvProblem
@@ -76,16 +75,16 @@ def test_halo_vjp_is_transpose_dot_test():
     def fwd(xl):
         return halo_exchange_1d(xl, "x", spatial_dim=0, lo=lo, hi=hi)
 
-    fn = dist.shard_map(fwd, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
-                        check_rep=False)
+    fn = jax.shard_map(fwd, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+                       check_vma=False)
     lhs = float(jnp.sum(fn(x) * y))
     (dx,) = jax.vjp(fn, x)[1](y)
     rhs = float(jnp.sum(x * dx))
     np.testing.assert_allclose(lhs, rhs, rtol=1e-6)
     # single rank: the accumulate is exactly the core slice
-    acc = dist.shard_map(
+    acc = jax.shard_map(
         lambda yl: halo_accumulate_1d(yl, "x", spatial_dim=0, lo=lo, hi=hi),
-        mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_rep=False)(y)
+        mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False)(y)
     np.testing.assert_allclose(dx, y[lo:lo + 4])
     np.testing.assert_allclose(acc, y[lo:lo + 4])
 
@@ -440,7 +439,7 @@ def test_compressed_psum_s8_on_the_wire():
     than the f32 all-reduce on a 2-rank axis, identical numerics."""
     run_in_subprocess("""
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.dist._compat import shard_map
+        from jax import shard_map
         from repro.dist.compress import compressed_psum
         from repro.launch.hlo_analysis import analyze_hlo
         mesh = Mesh(np.array(jax.devices()[:2]), ("pod",))
@@ -450,7 +449,7 @@ def test_compressed_psum_s8_on_the_wire():
             fn = shard_map(
                 lambda gl, el: compressed_psum(gl, "pod", el, wire=wire),
                 mesh=mesh, in_specs=(P("pod"), P("pod")),
-                out_specs=(P("pod"), P("pod")), check_rep=False)
+                out_specs=(P("pod"), P("pod")), check_vma=False)
             jfn = jax.jit(fn)
             out, err = jfn(g, jnp.zeros_like(g))
             txt = jfn.lower(g, jnp.zeros_like(g)).compile().as_text()
@@ -468,7 +467,7 @@ def test_compressed_psum_s8_on_the_wire():
         fn8 = shard_map(
             lambda gl, el: compressed_psum(gl, "pod", el, wire="s8"),
             mesh=mesh8, in_specs=(P("pod"), P("pod")),
-            out_specs=(P("pod"), P("pod")), check_rep=False)
+            out_specs=(P("pod"), P("pod")), check_vma=False)
         txt8 = jax.jit(fn8).lower(
             g8, jnp.zeros_like(g8)).compile().as_text()
         assert not s8_gather(txt8)

@@ -6,6 +6,8 @@ import numpy as np
 import pytest
 
 from repro.core.problem import resnet50_layers
+from repro.kernels import ops as kops
+from repro.kernels import tiling
 from repro.kernels.conv2d import conv2d_pallas
 from repro.kernels.matmul import matmul_pallas
 from repro.kernels.ops import conv2d_same, math_gcd_block, matmul
@@ -86,3 +88,50 @@ def test_block_plan_traffic_decreases_with_vmem():
     small = plan_blocks(p, vmem_elems=1 << 20)
     big = plan_blocks(p, vmem_elems=1 << 24)
     assert big.hbm_traffic <= small.hbm_traffic
+
+
+# ------------------------------------------- chip fit: tiling + VMEM rules
+
+
+@pytest.mark.parametrize("m,n,k,itemsize", [
+    (4, 128256, 2048, 2), (128, 8192, 2048, 2), (401408, 64, 576, 4),
+    (24, 40, 56, 4), (2304, 512, 25088, 4)])
+def test_matmul_plan_tiles_and_fits_vmem(m, n, k, itemsize):
+    bm, bn, bk = kops.matmul_plan(m, n, k, itemsize)
+    sub = tiling.sublanes(itemsize)
+    assert m % bm == 0 and n % bn == 0 and k % bk == 0
+    assert bm == m or bm % sub == 0
+    assert bn == n or bn % 128 == 0
+    assert bk == k or bk % 128 == 0
+    assert tiling.matmul_vmem_bytes(bm, bn, bk, itemsize) \
+        <= tiling.VMEM_BUDGET_BYTES
+
+
+def test_exclusion_rules_name_the_rule():
+    f32 = jnp.float32
+    assert kops.conv_exclusion((32, 64, 226, 226), (64, 64, 3, 3), (1, 1),
+                               "VALID", f32) == "image slab does not fit VMEM"
+    assert kops.conv_exclusion((32, 128, 58, 58), (128, 128, 3, 3), (1, 1),
+                               "VALID", f32) is None
+    assert "taps" in kops.conv_exclusion((64, 32, 58, 58), (64, 32, 56, 56),
+                                         (1, 1), "VALID", f32)
+    assert kops.conv_exclusion((4, 8, 9, 9), (8, 8, 3, 3), (2, 2),
+                               "VALID", f32) == "stride != 1"
+    assert kops.matmul_exclusion(15, 16, 16) == "extent % 8 != 0"
+    assert kops.matmul_exclusion(4, 128256, 2048, jnp.bfloat16) is None
+    # a lane extent with no 128-multiple divisor must be one block, and
+    # that block exceeds VMEM
+    assert kops.matmul_exclusion(8, 1_000_008, 8) == \
+        "no tiled block fits VMEM"
+
+
+def test_dispatch_table_records_choice_and_exclusion():
+    a = jnp.ones((15, 16), jnp.float32)
+    b = jnp.ones((16, 16), jnp.float32)
+    kops.local_matmul(a, b)
+    key = kops.matmul_key(15, 16, 16, jnp.float32)
+    assert key.endswith("@" + jax.devices()[0].device_kind)
+    ent = kops.dispatch_table()[key]
+    assert ent["impl"] == "xla"
+    assert ent["excluded"] == {"pallas": "extent % 8 != 0"}
+    assert ent["refused"] == {}

@@ -13,7 +13,7 @@ from repro.models.api import model_fns
 from repro.parallel import sharding as shd
 
 
-# fake_mesh is the version-tolerant AbstractMesh factory fixture from
+# fake_mesh is the AbstractMesh factory fixture from
 # conftest.py (tests run single-device per the dry-run contract).
 
 
