@@ -71,6 +71,11 @@ whose backward pass transposes the forward communication structure
 ``conv_comm_elems`` / ``conv_train_comm_elems`` give the analytic
 per-device wire volumes of the forward and forward+backward schedules that
 ``launch.hlo_analysis`` numbers are validated against.
+
+The local contractions run under ``jax.named_scope`` ``conv.fwd``,
+``conv.dx`` and ``conv.dw``, which the compiled program keeps in each
+instruction's ``op_name`` metadata: a device trace or the compiled HLO
+tells the three phases apart.
 """
 
 from __future__ import annotations
@@ -246,8 +251,11 @@ def _local_conv(xl, wl, *, sizes, stride, plans, schedule, pallas=True):
     # before any gather so boundary traffic is minimal
     _, xl, _ = _halo_and_window(xl, plans)
     # per-step local contraction through the Pallas/XLA kernel dispatcher
-    conv = functools.partial(kops.local_conv2d, stride=stride,
-                             padding="VALID", prefer_pallas=pallas)
+    def conv(a, b):
+        with jax.named_scope("conv.fwd"):
+            return kops.local_conv2d(a, b, stride=stride, padding="VALID",
+                                     prefer_pallas=pallas)
+
     if schedule == "ring2":
         out = _conv_fwd_ring2(xl, wl, pb=pb, pk=pk, conv=conv)
         if pc > 1:
@@ -286,15 +294,17 @@ def _dx_local(gl, wg, *, stride):
     Stride-1 is a plain VALID conv on the edge-padded cotangent and goes
     through the kernel dispatcher; strided needs ``lhs_dilation``."""
     kh, kw = wg.shape[2], wg.shape[3]
-    if tuple(stride) == (1, 1):
-        gp = jnp.pad(gl, ((0, 0), (0, 0), (kh - 1, kh - 1),
-                          (kw - 1, kw - 1)))
-        wt = lax.rev(wg, (2, 3)).transpose(1, 0, 2, 3)
-        return kops.local_conv2d(gp, wt, stride=(1, 1), padding="VALID")
-    return lax.conv_general_dilated(
-        gl, lax.rev(wg, (2, 3)), window_strides=(1, 1),
-        padding=((kh - 1, kh - 1), (kw - 1, kw - 1)), lhs_dilation=stride,
-        dimension_numbers=("NCHW", "IOHW", "NCHW"))
+    with jax.named_scope("conv.dx"):
+        if tuple(stride) == (1, 1):
+            gp = jnp.pad(gl, ((0, 0), (0, 0), (kh - 1, kh - 1),
+                              (kw - 1, kw - 1)))
+            wt = lax.rev(wg, (2, 3)).transpose(1, 0, 2, 3)
+            return kops.local_conv2d(gp, wt, stride=(1, 1),
+                                     padding="VALID")
+        return lax.conv_general_dilated(
+            gl, lax.rev(wg, (2, 3)), window_strides=(1, 1),
+            padding=((kh - 1, kh - 1), (kw - 1, kw - 1)),
+            lhs_dilation=stride, dimension_numbers=("NCHW", "IOHW", "NCHW"))
 
 
 def _dw_local(xg, gl, *, stride):
@@ -302,15 +312,16 @@ def _dw_local(xg, gl, *, stride):
     In slides under the stride-dilated dOut, contracting over N.
     Stride-1 is the N/C-transposed VALID conv and goes through the kernel
     dispatcher; strided needs ``rhs_dilation``."""
-    if tuple(stride) == (1, 1):
-        out = kops.local_conv2d(xg.transpose(1, 0, 2, 3),
-                                gl.transpose(1, 0, 2, 3),
-                                stride=(1, 1), padding="VALID")
+    with jax.named_scope("conv.dw"):
+        if tuple(stride) == (1, 1):
+            out = kops.local_conv2d(xg.transpose(1, 0, 2, 3),
+                                    gl.transpose(1, 0, 2, 3),
+                                    stride=(1, 1), padding="VALID")
+            return out.transpose(1, 0, 2, 3)
+        out = lax.conv_general_dilated(
+            xg, gl, window_strides=(1, 1), padding="VALID",
+            rhs_dilation=stride, dimension_numbers=("CNHW", "IOHW", "NCHW"))
         return out.transpose(1, 0, 2, 3)
-    out = lax.conv_general_dilated(
-        xg, gl, window_strides=(1, 1), padding="VALID",
-        rhs_dilation=stride, dimension_numbers=("CNHW", "IOHW", "NCHW"))
-    return out.transpose(1, 0, 2, 3)
 
 
 def _conv_bwd_ring2(xwin, wl, gl, *, pb, pk, stride, psp):

@@ -228,6 +228,11 @@ def make_resilient_train_loop(optimizer: AdamW, rcfg: ResilienceConfig,
     step), ``start_step``/``end_step``, ``grid``, ``preempted`` (True
     when a SIGTERM stopped the loop after the emergency save), and
     ``events`` (the structured :class:`FaultEvent` list).
+
+    Under ``jax.profiler`` each iteration is a ``train.step`` step span
+    (``step_num``) holding ``train.batch``, ``train.dispatch``,
+    ``train.sync`` (the ``float(loss)`` wait) and ``train.after`` (the
+    straggler monitor and checkpoint saves).
     """
     import jax
 
@@ -320,37 +325,44 @@ def make_resilient_train_loop(optimizer: AdamW, rcfg: ResilienceConfig,
                 if saver.triggered:
                     preempted = True
                     break
-                if wd is not None:
-                    wd.arm(step)
-                try:
-                    if injector is not None:
-                        injector.fire("step", step, ctx)
-                    if saver.triggered:  # injected/real SIGTERM landed
-                        preempted = True
-                        break
-                    batch = batch_fn(step)
-                    t0 = time.monotonic()
-                    state, metrics = step_fn(state, batch)
-                    loss = float(metrics["loss"])  # blocks on the step
-                finally:
+                with jax.profiler.StepTraceAnnotation("train.step",
+                                                      step_num=step):
                     if wd is not None:
-                        wd.disarm()
-                dt = time.monotonic() - t0
-                losses.append(loss)
-                holder["state"], holder["done"] = state, step + 1
-                if monitor.observe(step, dt):
-                    log.emit(FaultEvent(
-                        kind="straggler", step=step,
-                        detail=f"dt {dt:.3f}s vs ema "
-                               f"{monitor.stats.ema:.3f}s — "
-                               f"checkpointing"))
-                    if mgr is not None:
-                        with save_lock:
-                            mgr.save(state, step + 1, async_=True)
-                    monitor.consecutive = 0
-                elif mgr is not None and (step + 1) % rcfg.ckpt_every == 0:
-                    with save_lock:
-                        mgr.save(state, step + 1, async_=True)
+                        wd.arm(step)
+                    try:
+                        if injector is not None:
+                            injector.fire("step", step, ctx)
+                        if saver.triggered:  # injected/real SIGTERM landed
+                            preempted = True
+                            break
+                        with jax.profiler.TraceAnnotation("train.batch"):
+                            batch = batch_fn(step)
+                        t0 = time.monotonic()
+                        with jax.profiler.TraceAnnotation("train.dispatch"):
+                            state, metrics = step_fn(state, batch)
+                        with jax.profiler.TraceAnnotation("train.sync"):
+                            loss = float(metrics["loss"])  # blocks
+                    finally:
+                        if wd is not None:
+                            wd.disarm()
+                    with jax.profiler.TraceAnnotation("train.after"):
+                        dt = time.monotonic() - t0
+                        losses.append(loss)
+                        holder["state"], holder["done"] = state, step + 1
+                        if monitor.observe(step, dt):
+                            log.emit(FaultEvent(
+                                kind="straggler", step=step,
+                                detail=f"dt {dt:.3f}s vs ema "
+                                       f"{monitor.stats.ema:.3f}s — "
+                                       f"checkpointing"))
+                            if mgr is not None:
+                                with save_lock:
+                                    mgr.save(state, step + 1, async_=True)
+                            monitor.consecutive = 0
+                        elif (mgr is not None
+                              and (step + 1) % rcfg.ckpt_every == 0):
+                            with save_lock:
+                                mgr.save(state, step + 1, async_=True)
         finally:
             if wd is not None:
                 wd.close()

@@ -63,7 +63,6 @@ class Request:
     max_new: int
     out: List[int] = field(default_factory=list)
     prefill_ms: float = 0.0
-    step_ms: List[float] = field(default_factory=list)
     deadline_s: Optional[float] = None
     status: str = "ok"
     error: str = ""
@@ -78,6 +77,9 @@ class ContinuousEngine:
     grid (`models/lm.py` ``dist_mesh=`` path); ``None`` serves dense —
     the two run the identical queue/prefill/decode schedule, which is
     what makes the smoke-mode token comparison meaningful.
+
+    Admission and decode record ``jax.profiler`` spans (``serve.admit``
+    and ``serve.decode`` with their phases; ``docs/serving.md``).
     """
 
     def __init__(self, cfg, params, *, slots: int, max_seq: int,
@@ -206,7 +208,7 @@ class ContinuousEngine:
         return min(((plen + b - 1) // b) * b, self.max_seq)
 
     def _admit(self) -> None:
-        jnp = self._jnp
+        jax, jnp = self._jax, self._jnp
         for slot in range(self.slots):
             if self.active[slot] is not None:
                 continue
@@ -215,23 +217,30 @@ class ContinuousEngine:
                 break
             plen = len(req.prompt)
             padded = self._padded_len(plen)
-            toks = jnp.asarray(
-                [req.prompt + [0] * (padded - plen)], jnp.int32)
-            t0 = time.perf_counter()
-            logits, stage = self._prefill_fn(self.params, toks, plen - 1)
-            first = int(logits[0, 0].argmax())
-            req.prefill_ms = (time.perf_counter() - t0) * 1e3
-            if self.keep_logits:
-                req.prefill_logits = logits[0, 0]
-            self.cache["k"] = self.cache["k"].at[:, slot].set(
-                stage["k"][:, 0])
-            self.cache["v"] = self.cache["v"].at[:, slot].set(
-                stage["v"][:, 0])
-            self.cache["len"] = self.cache["len"].at[slot].set(plen)
-            self.next_tok = self.next_tok.at[slot, 0].set(first)
-            self.active[slot] = req
-            req.out.append(first)
-            self._maybe_retire(slot, first)
+            queued_ms = (time.monotonic() - req.t_submit) * 1e3
+            with jax.profiler.TraceAnnotation(
+                    "serve.admit", rid=req.rid, bucket=padded,
+                    queued_ms=queued_ms):
+                toks = jnp.asarray(
+                    [req.prompt + [0] * (padded - plen)], jnp.int32)
+                with jax.profiler.TraceAnnotation("serve.prefill"):
+                    t0 = time.perf_counter()
+                    logits, stage = self._prefill_fn(self.params, toks,
+                                                     plen - 1)
+                    first = int(logits[0, 0].argmax())
+                    req.prefill_ms = (time.perf_counter() - t0) * 1e3
+                if self.keep_logits:
+                    req.prefill_logits = logits[0, 0]
+                with jax.profiler.TraceAnnotation("serve.scatter"):
+                    self.cache["k"] = self.cache["k"].at[:, slot].set(
+                        stage["k"][:, 0])
+                    self.cache["v"] = self.cache["v"].at[:, slot].set(
+                        stage["v"][:, 0])
+                    self.cache["len"] = self.cache["len"].at[slot].set(plen)
+                    self.next_tok = self.next_tok.at[slot, 0].set(first)
+                self.active[slot] = req
+                req.out.append(first)
+                self._maybe_retire(slot, first)
 
     def _maybe_retire(self, slot: int, tok: int) -> None:
         req = self.active[slot]
@@ -250,38 +259,52 @@ class ContinuousEngine:
     # ------------------------------------------------------------ decode --
 
     def _decode_once(self) -> None:
-        jnp = self._jnp
-        t0 = time.perf_counter()
-        if self._cache_sh is not None:
-            # conform the cache to the grid layout (KV over the m/slot
-            # axis); a no-op in steady state when it is last decode's
-            # output, a real reshard right after an admission scatter.
-            # Without it pjit re-specializes per input sharding combo.
-            self.cache = self._jax.device_put(self.cache, self._cache_sh)
-        logits, self.cache = self._decode_fn(self.params, self.cache,
-                                             self.next_tok)
-        nxt = [int(v) for v in logits[:, 0].argmax(-1)]  # host sync
-        dt = (time.perf_counter() - t0) * 1e3
-        self.decode_ms.append(dt)
-        now = time.monotonic()
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            req.out.append(nxt[slot])
-            req.step_ms.append(dt)
-            self.next_tok = self.next_tok.at[slot, 0].set(nxt[slot])
-            self._maybe_retire(slot, nxt[slot])
-            if self.active[slot] is not None and self._expired(req, now):
-                # per-request deadline: retire the timed-out slot so it
-                # recycles instead of decoding for a caller that's gone
-                self._retire_slot(
-                    slot, "deadline",
-                    f"deadline {req.deadline_s}s exceeded after "
-                    f"{len(req.out)} tokens")
-        # idle slots decode garbage rows; pin their length so the ring
-        # write can never run off the cache end while a slot sits empty
-        mask = jnp.asarray([r is not None for r in self.active])
-        self.cache["len"] = jnp.where(mask, self.cache["len"], 0)
+        jax, jnp = self._jax, self._jnp
+        with jax.profiler.TraceAnnotation(
+                "serve.decode", step=len(self.decode_ms),
+                active=sum(r is not None for r in self.active),
+                queued=len(self.queue)):
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("serve.decode.launch"):
+                if self._cache_sh is not None:
+                    # conform the cache to the grid layout (KV over the
+                    # m/slot axis); a no-op in steady state when it is
+                    # last decode's output, a real reshard right after an
+                    # admission scatter.  Without it pjit re-specializes
+                    # per input sharding combo.
+                    self.cache = jax.device_put(self.cache, self._cache_sh)
+                logits, self.cache = self._decode_fn(self.params, self.cache,
+                                                     self.next_tok)
+                # iterating a device array dispatches its per-slot slices;
+                # they go out before the wait, as they would unobserved
+                rows = list(logits[:, 0].argmax(-1))
+            with jax.profiler.TraceAnnotation("serve.decode.wait"):
+                jax.block_until_ready(rows)
+            with jax.profiler.TraceAnnotation("serve.decode.read"):
+                nxt = [int(v) for v in rows]  # host sync
+            self.decode_ms.append((time.perf_counter() - t0) * 1e3)
+            with jax.profiler.TraceAnnotation("serve.decode.bookkeep"):
+                now = time.monotonic()
+                for slot, req in enumerate(self.active):
+                    if req is None:
+                        continue
+                    req.out.append(nxt[slot])
+                    self.next_tok = self.next_tok.at[slot, 0].set(nxt[slot])
+                    self._maybe_retire(slot, nxt[slot])
+                    if (self.active[slot] is not None
+                            and self._expired(req, now)):
+                        # per-request deadline: retire the timed-out slot
+                        # so it recycles instead of decoding for a caller
+                        # that's gone
+                        self._retire_slot(
+                            slot, "deadline",
+                            f"deadline {req.deadline_s}s exceeded after "
+                            f"{len(req.out)} tokens")
+                # idle slots decode garbage rows; pin their length so the
+                # ring write can never run off the cache end while a slot
+                # sits empty
+                mask = jnp.asarray([r is not None for r in self.active])
+                self.cache["len"] = jnp.where(mask, self.cache["len"], 0)
 
     def warmup(self, prompt_lens: List[int]) -> None:
         """Compile prefill (per bucket) and decode ahead of serving so
