@@ -41,6 +41,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from repro.configs import get_config
 
 _TRANSFORMER_FAMILIES = ("dense", "moe", "vlm")
@@ -128,6 +130,14 @@ class ContinuousEngine:
                                   dist_mesh=dist_mesh,
                                   dist_schedule=dist_schedule)
 
+        def _pick(logits):
+            # greedy pick: exactly the next decode's [slots, 1] tokens
+            return logits[:, 0].argmax(-1).astype(jnp.int32)[:, None]
+
+        def _mask_len(lens, live):
+            return jnp.where(live, lens, 0)
+
+        pin = {}
         if dist_mesh is not None:
             # pin boundary shardings: the KV cache rides the m (slot)
             # axis, everything else replicates.  Without the pin, pjit
@@ -152,10 +162,18 @@ class ContinuousEngine:
             self._prefill_fn = jax.jit(_prefill,
                                        in_shardings=(rep, rep, rep),
                                        out_shardings=(rep, rep))
+            pin = {"out_shardings": rep}
+            self._live_sh = rep
         else:
             self._cache_sh = None
+            self._live_sh = None
             self._decode_fn = jax.jit(_decode, donate_argnums=1)
             self._prefill_fn = jax.jit(_prefill)
+        # the token feed and the idle-slot mask run as small programs of
+        # their own beside the decode program, with outputs pinned to the
+        # layout the decode takes them in (see the pin above)
+        self._pick_fn = jax.jit(_pick, **pin)
+        self._mask_fn = jax.jit(_mask_len, **pin)
 
     # ------------------------------------------------------------- queue --
 
@@ -259,7 +277,11 @@ class ContinuousEngine:
     # ------------------------------------------------------------ decode --
 
     def _decode_once(self) -> None:
-        jax, jnp = self._jax, self._jnp
+        """One decode step over every slot.  The greedy tokens stay on
+        the device as the next step's input; the host reads them once, as
+        one [slots, 1] array, for its bookkeeping.  Dispatches and host
+        reads per step do not depend on how many slots are occupied."""
+        jax = self._jax
         with jax.profiler.TraceAnnotation(
                 "serve.decode", step=len(self.decode_ms),
                 active=sum(r is not None for r in self.active),
@@ -275,13 +297,11 @@ class ContinuousEngine:
                     self.cache = jax.device_put(self.cache, self._cache_sh)
                 logits, self.cache = self._decode_fn(self.params, self.cache,
                                                      self.next_tok)
-                # iterating a device array dispatches its per-slot slices;
-                # they go out before the wait, as they would unobserved
-                rows = list(logits[:, 0].argmax(-1))
+                self.next_tok = self._pick_fn(logits)
             with jax.profiler.TraceAnnotation("serve.decode.wait"):
-                jax.block_until_ready(rows)
+                self.next_tok.block_until_ready()
             with jax.profiler.TraceAnnotation("serve.decode.read"):
-                nxt = [int(v) for v in rows]  # host sync
+                nxt = jax.device_get(self.next_tok)[:, 0].tolist()
             self.decode_ms.append((time.perf_counter() - t0) * 1e3)
             with jax.profiler.TraceAnnotation("serve.decode.bookkeep"):
                 now = time.monotonic()
@@ -289,7 +309,6 @@ class ContinuousEngine:
                     if req is None:
                         continue
                     req.out.append(nxt[slot])
-                    self.next_tok = self.next_tok.at[slot, 0].set(nxt[slot])
                     self._maybe_retire(slot, nxt[slot])
                     if (self.active[slot] is not None
                             and self._expired(req, now)):
@@ -303,8 +322,13 @@ class ContinuousEngine:
                 # idle slots decode garbage rows; pin their length so the
                 # ring write can never run off the cache end while a slot
                 # sits empty
-                mask = jnp.asarray([r is not None for r in self.active])
-                self.cache["len"] = jnp.where(mask, self.cache["len"], 0)
+                self.cache["len"] = self._mask_fn(self.cache["len"],
+                                                  self._live_mask())
+
+    def _live_mask(self):
+        """The occupied slots as a device bool vector, in one transfer."""
+        return self._jax.device_put(
+            np.array([r is not None for r in self.active]), self._live_sh)
 
     def warmup(self, prompt_lens: List[int]) -> None:
         """Compile prefill (per bucket) and decode ahead of serving so
@@ -315,7 +339,10 @@ class ContinuousEngine:
                              pl - 1)
         throwaway = self._lm.init_cache(self.cfg, self.slots,
                                         self.max_seq, per_slot=True)
-        self._decode_fn(self.params, throwaway, self.next_tok)
+        logits, throwaway = self._decode_fn(self.params, throwaway,
+                                            self.next_tok)
+        self._pick_fn(logits)
+        self._mask_fn(throwaway["len"], self._live_mask())
 
     # ----------------------------------------------------- wedge handling --
 

@@ -231,20 +231,122 @@ def test_engine_admission_rejects_oversized():
     assert len(eng.queue) == 1
 
 
-def test_engine_slot_recycling_serves_all():
-    # 5 requests through 2 slots: every request retires, with exactly
-    # max_new tokens each (no EOS id set), and the engine drains clean
+def _greedy_reference(cfg, params, reqs, max_seq):
+    """Each request decoded alone on the batch-1 scalar-length cache."""
+    prefill = jax.jit(lambda p, c, t: lm_mod.prefill(p, cfg, c, t))
+    step = jax.jit(lambda p, c, t: lm_mod.decode_step(p, cfg, c, t))
+    outs = {}
+    for r in reqs:
+        logits, cache = prefill(params, lm_mod.init_cache(cfg, 1, max_seq),
+                                jnp.asarray([r.prompt], jnp.int32))
+        out = [int(logits[0, -1].argmax())]
+        while len(out) < r.max_new:
+            logits, cache = step(params, cache,
+                                 jnp.asarray([[out[-1]]], jnp.int32))
+            out.append(int(logits[0, -1].argmax()))
+        outs[r.rid] = out
+    return outs
+
+
+@pytest.mark.parametrize("slots,requests,prompt_len,gen", [
+    (2, 5, 6, 4), (4, 9, 7, 9), (3, 7, 5, 12)],
+    ids=["2slots", "4slots", "3slots-long"])
+def test_engine_slot_recycling_serves_all(slots, requests, prompt_len, gen):
+    # more requests than slots, of varied lengths: every request retires
+    # with exactly max_new tokens each (no EOS id set), the engine drains
+    # clean, and the tokens fed back on the device through retirement
+    # and re-admission into the same slot are each request's own greedy
+    # decode
     cfg = _smoke_cfg()
-    eng = _engine(cfg, slots=2, max_seq=24)
-    reqs = _make_requests(cfg, requests=5, prompt_len=6, gen=4, seed=0)
+    eng = _engine(cfg, slots=slots, max_seq=32)
+    reqs = _make_requests(cfg, requests=requests, prompt_len=prompt_len,
+                          gen=gen, seed=1)
     res = eng.serve(reqs)
-    assert res["n_requests"] == 5
-    assert sorted(res["tokens"]) == [0, 1, 2, 3, 4]
+    assert res["n_requests"] == requests
+    assert sorted(res["tokens"]) == list(range(requests))
     for r in reqs:
         assert len(r.out) == r.max_new, r.rid
     assert not eng.queue and all(s is None for s in eng.active)
     assert res["n_tokens"] == sum(r.max_new for r in reqs)
     assert res["tokens_per_s"] > 0
+    assert res["tokens"] == _greedy_reference(cfg, eng.params, reqs, 32)
+
+
+def _decode_cost(eng):
+    """Calls of each jitted engine program and host reads of device
+    arrays in one ``_decode_once``, run with implicit transfers
+    disallowed.  Every read of a ``jax.Array`` to the host goes through
+    ``ArrayImpl._value``; reads are counted there because the CPU backend
+    does not enforce the device-to-host transfer guard."""
+    from jax._src.array import ArrayImpl
+    value = ArrayImpl._value
+    calls, reads = {}, []
+
+    def counted(name, fn):
+        def call(*args):
+            calls[name] = calls.get(name, 0) + 1
+            return fn(*args)
+        return call
+
+    def read(arr):
+        reads.append(arr.shape)
+        return value.fget(arr)
+
+    jitted = {k: v for k, v in vars(eng).items() if hasattr(v, "lower")}
+    try:
+        for k, v in jitted.items():
+            setattr(eng, k, counted(k, v))
+        ArrayImpl._value = property(read)
+        with jax.transfer_guard("disallow"):
+            eng._decode_once()
+    finally:
+        ArrayImpl._value = value
+        for k, v in jitted.items():
+            setattr(eng, k, v)
+    return calls, reads
+
+
+def test_decode_step_cost_independent_of_active_slots():
+    # a decode step dispatches the same programs, once each, and reads
+    # the [slots, 1] tokens to the host once, whether 1 or 3 of 4 slots
+    # are occupied: nothing per slot goes to or comes from the device
+    cfg = _smoke_cfg()
+    eng = _engine(cfg, slots=4, max_seq=32)
+    reqs = [Request(rid=i, prompt=[1 + i, 2, 3], max_new=12)
+            for i in range(3)]
+    eng.submit(reqs[0])
+    eng._admit()
+    eng._decode_once()                       # compiles outside the count
+    one = _decode_cost(eng)
+    for r in reqs[1:]:
+        eng.submit(r)
+    eng._admit()
+    assert sum(r is not None for r in eng.active) == 3
+    three = _decode_cost(eng)
+    assert one == three
+    calls, reads = one
+    assert calls["_decode_fn"] == 1 and set(calls.values()) == {1}
+    assert reads == [(4, 1)]
+    assert [len(r.out) for r in reqs] == [4, 2, 2]
+
+
+def test_idle_slot_len_stays_zero():
+    # a slot whose request retired keeps decoding garbage rows; its
+    # length is masked back to 0 every step so it never runs off the
+    # cache, while the occupied slot advances one position a step
+    cfg = _smoke_cfg()
+    eng = _engine(cfg, slots=4, max_seq=32)
+    short = Request(rid=0, prompt=[1, 2, 3], max_new=2)
+    long = Request(rid=1, prompt=[4, 5, 6, 7], max_new=12)
+    eng.submit(short)
+    eng.submit(long)
+    eng._admit()
+    eng._decode_once()
+    assert eng.active[0] is None and eng.retired == [short]
+    for step in range(2, 8):
+        eng._decode_once()
+        np.testing.assert_array_equal(np.asarray(eng.cache["len"]),
+                                      [0, 4 + step, 0, 0])
 
 
 def test_engine_eos_frees_slot():
